@@ -300,6 +300,9 @@ def main() -> int:
             file=sys.stderr,
         )
         return 2
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     failures = []
     ran = 0
     for name in names:

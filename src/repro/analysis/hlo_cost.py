@@ -497,21 +497,6 @@ class CostAnalyzer:
         return out
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions.
-
-    Newer jax returns a one-element list of per-program dicts where older
-    versions returned the bare dict (and ``None`` when unavailable); this
-    always hands back a plain dict so callers can ``.get("flops")``.
-    """
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
-    return dict(ca)
-
-
 def analyze(hlo_text: str) -> HloCost:
     """Cost of the entry computation, trip-count aware."""
     comps, entry = parse_module(hlo_text)

@@ -7,12 +7,10 @@ are masked out of the mean), and returns the mean NLL — a drop-in for
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.fused_ce.kernel import fused_ce_kernel
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def fused_ce(x, table, labels, *, bt: int = 128, bv: int = 512):
@@ -29,7 +27,7 @@ def fused_ce(x, table, labels, *, bt: int = 128, bv: int = 512):
     nll = fused_ce_kernel(
         x, table, labels.astype(jnp.int32)[:, None],
         bt=bt, bv=min(bv, table.shape[0] + (-table.shape[0]) % 8),
-        interpret=not _ON_TPU,
+        interpret=interpret_mode(),
     )[:, 0]
     if pad:
         nll = nll[:T]

@@ -7,12 +7,10 @@ padding queries are cropped after the call).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.swa_attention.kernel import swa_attention_kernel
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def swa_attention(q, k, v, *, window: int, bq: int = 128, bk: int = 128):
@@ -34,7 +32,7 @@ def swa_attention(q, k, v, *, window: int, bq: int = 128, bk: int = 128):
     kT = k.transpose(0, 2, 1, 3)
     vT = v.transpose(0, 2, 1, 3)
     out = swa_attention_kernel(
-        qT, kT, vT, window=window, bq=bq, bk=bk, interpret=not _ON_TPU
+        qT, kT, vT, window=window, bq=bq, bk=bk, interpret=interpret_mode()
     )
     out = out.transpose(0, 2, 1, 3)
     return out[:, :s] if pad else out

@@ -75,7 +75,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, opt: bool, out_dir: Pat
     t_compile = time.time() - t0
 
     ma = compiled.memory_analysis()
-    ca = hlo_cost.xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo_text = compiled.as_text()
     cost = hlo_cost.analyze(hlo_text)
 

@@ -21,7 +21,10 @@ Three fault families, matched to the three robustness layers
   resume from the latest complete checkpoint, strictly monotone rollup
   counters across the restart, full round target reached.  The CLI
   (``python -m repro.launch.faults``) is the CI kill-and-resume smoke
-  step.
+  step.  The driver's own process never initialises a JAX backend (it
+  only reads telemetry files and checkpoint directories): a chip
+  belongs to one process at a time, and here the serving children need
+  it.
 """
 from __future__ import annotations
 

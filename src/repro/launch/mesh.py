@@ -7,6 +7,15 @@ process sets ``--xla_force_host_platform_device_count=512``.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the steps place arrays through
+    in/out shardings and sharding constraints, which is Auto-mode
+    partitioning (jax's default axis type is Explicit)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,14 +23,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (2, 16, 16) = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over the locally available devices (tests/examples)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def make_fleet_mesh(shards: int | None = None):
@@ -38,4 +47,4 @@ def make_fleet_mesh(shards: int | None = None):
     if n > avail:
         raise ValueError(f"asked for {n} fleet shards but only {avail} "
                          f"devices are visible")
-    return jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
+    return _mesh((n,), ("data",), jax.devices()[:n])

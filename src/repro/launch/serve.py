@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, list_archs, reduced
 from repro.data import synthetic as D
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 
 # the m=64 fleet scenarios --fleet can serve (repro.configs.paper_linreg)
@@ -197,6 +198,7 @@ def serve_decode(args) -> int:
 
 
 def main():
+    enable_compile_cache()
     args = parse_args()
     if args.fleet:
         raise SystemExit(serve_fleet(args))

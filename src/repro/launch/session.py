@@ -44,7 +44,6 @@ import dataclasses
 import json
 import threading
 import time
-import warnings
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Optional
 
@@ -52,12 +51,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.comm.rollup import CommRollup
-
-# CPU/backends without buffer donation warn per-compile; the session's
-# donation is an optimization, not a correctness requirement
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable"
-)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -78,7 +78,11 @@ def plan_run(
     inner_batch_shard: bool = False,
     cache_seq_shard: bool = False,
     microbatches: int = 1,
+    agents: Optional[int] = None,
 ) -> RunPlan:
+    """Plan one run; ``agents`` overrides the fleet size (default: the
+    product of the mesh's agent axes), replicating the agents over the
+    mesh."""
     if shape.name == "long_500k":
         cfg = long_context_variant(cfg)
     if remat or attn_q_block:
@@ -94,7 +98,7 @@ def plan_run(
     # data axis idle for activations — 16× replicated activation traffic
     # (EXPERIMENTS.md §Perf, qwen3 iter-2, hypothesis refuted).
     agent_axes: Tuple[str, ...] = ("pod", "data") if multipod else ("data",)
-    num_agents = int(math.prod(mesh.shape[a] for a in agent_axes))
+    num_agents = agents or int(math.prod(mesh.shape[a] for a in agent_axes))
     trigger = trigger or TriggerConfig(kind="gain_lookahead", lam=0.0)
     if comm is not None and not isinstance(comm, str):
         from repro.comm import CommPolicy
@@ -116,6 +120,8 @@ def plan_run(
         seq_shard=seq_shard, inner_batch_shard=inner_batch_shard,
         cache_seq_shard=cache_seq_shard,
     )
+    if agents:
+        rules["agent"] = None  # a custom agent count is replicated
     return RunPlan(
         cfg=cfg,
         shape=shape,

@@ -32,6 +32,7 @@ from repro.configs.base import InputShape, TriggerConfig
 from repro.core.api import init_train_state
 from repro.data import synthetic as D
 from repro.launch import steps as S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build
 from repro.optim import optimizers as opt_lib
@@ -94,6 +95,7 @@ def _legacy_comm_spec(args) -> str:
 
 
 def main():
+    enable_compile_cache()
     args = parse_args()
     cfg = get_config(args.arch)
     if args.reduced:
@@ -114,13 +116,8 @@ def main():
                        kind="train")
     comm = args.comm or _legacy_comm_spec(args)
     plan = S.plan_run(cfg, shape, mesh, comm=comm, optimizer=args.optimizer,
-                      lr=args.lr, microbatches=args.microbatches)
-    import dataclasses
-    if args.agents:
-        plan = dataclasses.replace(
-            plan, num_agents=args.agents,
-            train_cfg=dataclasses.replace(plan.train_cfg, num_agents=args.agents))
-        plan.rules["agent"] = None  # replicated custom agent count
+                      lr=args.lr, microbatches=args.microbatches,
+                      agents=args.agents)
     print(f"arch={cfg.name} params≈{cfg.param_count()/1e6:.1f}M agents={plan.num_agents} "
           f"comm={comm!r} mesh={dict(mesh.shape)}")
 

@@ -53,7 +53,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.comm import ef_add, sketch_decode, sketch_encode, sketch_params
@@ -443,9 +442,9 @@ def make_sharded_train_step(
             "params": P(), "opt_state": P(), "mem": aspec,
             "ctrl": aspec, "net": aspec, "metrics": metric_specs,
         }
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )(*operands)
         new_state = TrainState(
             state.step + 1, out["params"], out["opt_state"],

@@ -3,7 +3,8 @@ with hypothesis property tests on the invariants."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.comm.compressors import dequantize_int8, fake_quantize, quantize_int8
 from repro.core.aggregation import masked_mean, masked_mean_quantized

@@ -16,7 +16,9 @@ from repro.kernels.swa_attention import ref as swa_ref
 # ----------------------------------------------------------------------
 
 @pytest.mark.parametrize(
-    "shape", [(7,), (1024,), (1000, 37), (8, 128), (3, 5, 17), (4096, 64)]
+    "shape",
+    # (1000, 300) spans more than one grid step (kernel.MAX_TILES tiles)
+    [(7,), (1024,), (1000, 37), (8, 128), (3, 5, 17), (4096, 64), (1000, 300)],
 )
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gain_reduce_matches_ref(shape, dtype, rng):
@@ -35,6 +37,29 @@ def test_gain_reduce_zero_padding_exact(rng):
     g = jax.random.normal(rng, (1025,))  # forces padding
     gsq, _ = gr_ops.gain_reduce(g, g)
     np.testing.assert_allclose(float(gsq), float(jnp.sum(g * g)), rtol=1e-6)
+
+
+def test_gain_reduce_unknown_platform_raises(monkeypatch, rng):
+    """The Pallas mode is chosen per call: a platform with no kernel
+    path raises instead of silently interpreting."""
+    g = jax.random.normal(rng, (1000, 37))
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        gr_ops.gain_reduce(g, g)
+
+
+def test_gain_reduce_follows_default_device(monkeypatch, rng):
+    """Under ``jax.default_device(cpu)`` the kernel interprets even when
+    the default backend is a TPU (the CPU reference runs beside the chip)."""
+    k1, k2 = jax.random.split(rng)
+    g = jax.random.normal(k1, (1000, 37))
+    h = jax.random.normal(k2, (1000, 37))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.default_device(jax.devices("cpu")[0]):
+        gsq, ghg = gr_ops.gain_reduce(g, h)
+    rsq, rhg = gr_ref.gain_reduce_ref(g, h)
+    np.testing.assert_allclose(float(gsq), float(rsq), rtol=1e-5)
+    np.testing.assert_allclose(float(ghg), float(rhg), rtol=1e-5, atol=1e-3)
 
 
 def test_gain_estimate_formula(rng):

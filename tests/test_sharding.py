@@ -3,7 +3,8 @@ exercised here; the 512-device meshes only exist inside the dry-run)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.sharding.rules import (

@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.optim import optimizers as O
 from repro.optim import schedules as SCH
@@ -185,9 +186,7 @@ def test_hlo_cost_scan_trip_multiplication():
     # XLA's own counter misses the scan body multiplicity — that's why
     # hlo_cost exists; guard that the discrepancy is still there (if XLA
     # fixes it someday this test will flag the redundancy).
-    xla = hlo_cost.xla_cost_analysis(
-        jax.jit(scanned).lower(x, w).compile()
-    )["flops"]
+    xla = jax.jit(scanned).lower(x, w).compile().cost_analysis()["flops"]
     assert xla < want / 2
 
 

@@ -318,61 +318,67 @@ def _make_epilogue(trig: TriggerFn, chain: CompressorChain, *, use_ef: bool,
         use_retx = use_chan and channel.retx_k > 0
         use_delay = use_chan and channel.depth > 0 and not use_retx
         eff_scale = scale
-        if use_retx:
-            from repro.net.channels import retx_round, stale_scale, tx_cost
+        with jax.named_scope("channel"):
+            if use_retx:
+                from repro.net.channels import retx_round, stale_scale, tx_cost
 
-            cost = tx_cost(grad, chain)
-            d, stale, pending, commit = retx_round(
-                channel, net, step, chan_scale, cost
-            )
-            eff_scale = stale_scale(scale, channel.boost, stale, adaptive)
-            if adaptive:
-                kw["delivered"] = d
-        elif use_delay:
-            from repro.net.channels import delay_round, stale_scale
+                cost = tx_cost(grad, chain)
+                d, stale, pending, commit = retx_round(
+                    channel, net, step, chan_scale, cost
+                )
+                eff_scale = stale_scale(scale, channel.boost, stale, adaptive)
+                if adaptive:
+                    kw["delivered"] = d
+            elif use_delay:
+                from repro.net.channels import delay_round, stale_scale
 
-            d, stale, commit = delay_round(channel, net, step, chan_scale)
-            eff_scale = stale_scale(scale, channel.boost, stale, adaptive)
-            if adaptive:
-                kw["delivered"] = d
-        elif use_chan:
-            from repro.net.channels import (
-                channel_round,
-                net_rows,
-                stale_scale,
-                tx_cost,
-            )
+                d, stale, commit = delay_round(channel, net, step, chan_scale)
+                eff_scale = stale_scale(scale, channel.boost, stale, adaptive)
+                if adaptive:
+                    kw["delivered"] = d
+            elif use_chan:
+                from repro.net.channels import (
+                    channel_round,
+                    net_rows,
+                    stale_scale,
+                    tx_cost,
+                )
 
-            cost = tx_cost(grad, chain)
-            d, stale, finalize = channel_round(
-                channel, net_rows(net), step, chan_scale, cost
-            )
-            eff_scale = stale_scale(scale, channel.boost, stale, adaptive)
+                cost = tx_cost(grad, chain)
+                d, stale, finalize = channel_round(
+                    channel, net_rows(net), step, chan_scale, cost
+                )
+                eff_scale = stale_scale(scale, channel.boost, stale, adaptive)
+                if adaptive:
+                    kw["delivered"] = d
+        with jax.named_scope("trigger"):
             if adaptive:
-                kw["delivered"] = d
-        if adaptive:
-            # the controller reads its row (or its static init when the
-            # state carries no slot — open-loop lam0 gating) and emits
-            # the updated row only when there is a slot to carry it
-            row = ctrl if use_ctrl else trig.ctrl0
-            (alpha, gain), new_row = trig(
-                params, grad, batch, local_loss, step, row, eff_scale, **kw
-            )
-            new_ctrl = new_row if use_ctrl else None
-        else:
-            alpha, gain = trig(params, grad, batch, local_loss, step,
-                               eff_scale, **kw)
-            new_ctrl = ctrl  # pass the (unused) row through unchanged
-        g_eff = ef_add(grad, ef_mem if use_ef else None)
-        sent = chain.compress_tree(g_eff) if chain else g_eff
+                # the controller reads its row (or its static init when
+                # the state carries no slot — open-loop lam0 gating) and
+                # emits the updated row only when there is a slot to
+                # carry it
+                row = ctrl if use_ctrl else trig.ctrl0
+                (alpha, gain), new_row = trig(
+                    params, grad, batch, local_loss, step, row, eff_scale,
+                    **kw
+                )
+                new_ctrl = new_row if use_ctrl else None
+            else:
+                alpha, gain = trig(params, grad, batch, local_loss, step,
+                                   eff_scale, **kw)
+                new_ctrl = ctrl  # pass the (unused) row through unchanged
+        with jax.named_scope("compress"):
+            g_eff = ef_add(grad, ef_mem if use_ef else None)
+            sent = chain.compress_tree(g_eff) if chain else g_eff
         if use_retx:
             # resolve the retransmit round: alpha becomes the realized
             # wire ATTEMPT (re-offers are priced in attempted bytes),
             # ``sent`` the payload the server actually receives, and
             # ``fold`` the expired buffered payload owed to EF
-            attempt, out_sent, delivered, fold, new_net = commit(
-                alpha, sent
-            )
+            with jax.named_scope("channel"):
+                attempt, out_sent, delivered, fold, new_net = commit(
+                    alpha, sent
+                )
             if ef_mem is None:
                 new_mem = None
             elif use_ef:
@@ -382,10 +388,11 @@ def _make_epilogue(trig: TriggerFn, chain: CompressorChain, *, use_ef: bool,
                 # owed); a retransmitting round contributes nothing new;
                 # the expired payload folds back WHOLE on final failure
                 a_cur = alpha * (1.0 - pending)
-                new_mem = jax.tree_util.tree_map(
-                    lambda ge, se, f: (ge - se) * a_cur + f,
-                    g_eff, sent, fold,
-                )
+                with jax.named_scope("compress"):
+                    new_mem = jax.tree_util.tree_map(
+                        lambda ge, se, f: (ge - se) * a_cur + f,
+                        g_eff, sent, fold,
+                    )
             else:
                 new_mem = jax.tree_util.tree_map(
                     jax.numpy.zeros_like, ef_mem
@@ -398,10 +405,12 @@ def _make_epilogue(trig: TriggerFn, chain: CompressorChain, *, use_ef: bool,
             # ``delivered`` its staleness-discounted application
             # weight — masked_mean then aggregates old payloads with
             # discounted weights, no new aggregation primitive
-            out_sent, delivered, new_net = commit(alpha * d, sent)
+            with jax.named_scope("channel"):
+                out_sent, delivered, new_net = commit(alpha * d, sent)
         elif use_chan:
             delivered = alpha * d
-            new_row = finalize(delivered)
+            with jax.named_scope("channel"):
+                new_row = finalize(delivered)
             # inside a delay-carrying bank the net operand is the
             # (row, line) pair; pass the (unused) line through so every
             # switch branch keeps a uniform output pytree
@@ -418,8 +427,9 @@ def _make_epilogue(trig: TriggerFn, chain: CompressorChain, *, use_ef: bool,
             # back (for delay lines d is the accept indicator: the EF
             # residual is priced on what entered the wire, not on what
             # matured this round)
-            new_mem = ef_residual(g_eff, sent, alpha,
-                                  delivered=d if use_chan else None)
+            with jax.named_scope("compress"):
+                new_mem = ef_residual(g_eff, sent, alpha,
+                                      delivered=d if use_chan else None)
         else:
             new_mem = jax.tree_util.tree_map(jax.numpy.zeros_like, ef_mem)
         if use_delay:

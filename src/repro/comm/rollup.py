@@ -122,6 +122,9 @@ class CommRollup:
         self._degradation: Dict[str, int] = {}
         self._restarts = 0
         self._rounds_live = 0
+        # seconds the serving loop spent in each stage of its rounds
+        # (sample, dispatch, wait, pull, rollup, checkpoint), cumulative
+        self._stage_seconds: Dict[str, float] = {}
 
     # ------------------------------------------------------------------
     # ingest
@@ -207,6 +210,14 @@ class CommRollup:
         with self._lock:
             self._restarts += 1
 
+    def record_stage_seconds(self, seconds: Dict[str, float]) -> None:
+        """Add the serving loop's seconds per round stage; exported as
+        ``fleet_stage_seconds_total{stage=...}`` once any exist."""
+        with self._lock:
+            for stage, secs in seconds.items():
+                self._stage_seconds[stage] = (
+                    self._stage_seconds.get(stage, 0.0) + float(secs))
+
     # ------------------------------------------------------------------
     # persistence (the FleetSession checkpoint path)
     # ------------------------------------------------------------------
@@ -235,6 +246,7 @@ class CommRollup:
                 "saw_churn": self._saw_churn,
                 "degradation": dict(self._degradation),
                 "restarts": self._restarts,
+                "stage_seconds": dict(self._stage_seconds),
             }
 
     def load_state(self, state: dict) -> None:
@@ -270,6 +282,8 @@ class CommRollup:
             self._degradation = {k: int(v) for k, v in
                                  state.get("degradation", {}).items()}
             self._restarts = int(state.get("restarts", 0))
+            self._stage_seconds = {k: float(v) for k, v in
+                                   state.get("stage_seconds", {}).items()}
 
     # ------------------------------------------------------------------
     # export
@@ -309,6 +323,10 @@ class CommRollup:
             if self._degradation:
                 snap["degradation_events"] = dict(
                     sorted(self._degradation.items()))
+            if any(self._stage_seconds.values()):
+                snap["stage_seconds"] = {
+                    k: round(v, 6)
+                    for k, v in sorted(self._stage_seconds.items())}
             att = self._counters.get("wire_bytes_attempted")
             if att:
                 # lossy channels: fraction of attempted bytes delivered
@@ -409,6 +427,14 @@ class CommRollup:
                 out.append(
                     f'fleet_degradation_events_total{{kind="{kind}"}} '
                     f"{_fmt(n)}")
+        if "stage_seconds" in s:
+            out.append("# HELP fleet_stage_seconds_total Seconds the serving "
+                       "loop spent in each round stage, cumulative.")
+            out.append("# TYPE fleet_stage_seconds_total counter")
+            for stage, secs in s["stage_seconds"].items():
+                out.append(
+                    f'fleet_stage_seconds_total{{stage="{stage}"}} '
+                    f"{_fmt(secs)}")
         for metric, kind, help_, key in (
             ("fleet_tier_agents", "gauge", "Agents in the tier.", "agents"),
             ("fleet_tier_tx_rate", "gauge",

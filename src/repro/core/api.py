@@ -534,7 +534,7 @@ def make_triggered_train_step(
         return main, g
 
     def trigger_call(trig, is_adaptive, use_ctrl, params, g, agent_batch,
-                     main, step, ctrl_row, scale, delivered=None):
+                     main, step, ctrl_row, scale, delivered=None, pre=None):
         """One trigger evaluation under either protocol.
 
         Returns ``(alpha, gain, new_ctrl_row)`` where the row is
@@ -548,15 +548,19 @@ def make_triggered_train_step(
         controllers re-gate under loss.  Fixed triggers never see it
         (their threshold is staleness-scaled upstream instead), and the
         channel-free default (``None``) adds no kwarg — the trigger
-        traces its pre-channel ops."""
+        traces its pre-channel ops.  ``pre`` is the trigger's gain
+        precursor when the caller computed it (``trig.prologue``, the
+        same ops the trigger would run itself)."""
+        kw = {} if pre is None else {"pre": pre}
         if is_adaptive:
             row = ctrl_row if use_ctrl else trig.ctrl0
-            kw = {} if delivered is None else {"delivered": delivered}
+            if delivered is not None:
+                kw["delivered"] = delivered
             (alpha, gain), new_row = trig(
                 params, g, agent_batch, main, step, row, scale, **kw
             )
             return alpha, gain, (new_row if use_ctrl else None)
-        alpha, gain = trig(params, g, agent_batch, main, step, scale)
+        alpha, gain = trig(params, g, agent_batch, main, step, scale, **kw)
         return alpha, gain, (ctrl_row if use_ctrl else None)
 
     def train_step(state: TrainState, batch, scale=None, chan_scale=None):
@@ -577,30 +581,43 @@ def make_triggered_train_step(
             if needs_ctrl and not use_ctrl:
                 _warn_ctrl_state_missing()
 
+            # the trigger's gain precursor (probe forward, ‖g‖², ...),
+            # evaluated under its own scope and handed to the trigger
+            probe = getattr(trigger, "prologue", None)
+
             def per_agent(agent_batch, ctrl_row, net_row):
-                main, g = grad_prologue(state.params, agent_batch, False)
+                with jax.named_scope("prologue"):
+                    main, g = grad_prologue(state.params, agent_batch, False)
                 if use_net:
                     # channel draw FIRST (delivery independent of this
                     # round's alpha); the staleness factor escalates a
                     # starved agent's effective threshold/target
-                    cost = tx_cost(g, chain)
-                    d, stale, finalize = channel_round(
-                        channel, net_row, state.step, chan_scale, cost
-                    )
-                    eff_scale = stale_scale(
-                        scale, channel.boost, stale, adaptive
-                    )
+                    with jax.named_scope("channel"):
+                        cost = tx_cost(g, chain)
+                        d, stale, finalize = channel_round(
+                            channel, net_row, state.step, chan_scale, cost
+                        )
+                        eff_scale = stale_scale(
+                            scale, channel.boost, stale, adaptive
+                        )
                 else:
                     d, eff_scale = None, scale
-                alpha, gain, new_row = trigger_call(
-                    trigger, adaptive, use_ctrl, state.params, g,
-                    agent_batch, main, state.step, ctrl_row, eff_scale,
-                    delivered=d if adaptive else None,
-                )
+                pre = None
+                if probe is not None:
+                    with jax.named_scope("probe"):
+                        pre = probe(state.params, g, agent_batch, main)
+                with jax.named_scope("trigger"):
+                    alpha, gain, new_row = trigger_call(
+                        trigger, adaptive, use_ctrl, state.params, g,
+                        agent_batch, main, state.step, ctrl_row, eff_scale,
+                        delivered=d if adaptive else None, pre=pre,
+                    )
                 if use_net:
                     delivered = alpha * d
+                    with jax.named_scope("channel"):
+                        new_net_row = finalize(delivered)
                     return (main, g, alpha, gain, new_row, d, delivered,
-                            finalize(delivered))
+                            new_net_row)
                 return main, g, alpha, gain, new_row
 
             in_axes = (0, 0 if use_ctrl else None, 0 if use_net else None)
@@ -623,15 +640,16 @@ def make_triggered_train_step(
                 use_ef = needs_ef and state.ef_memory is not None
                 if needs_ef and not use_ef:
                     _warn_ef_memory_missing()
-                g_eff = ef_add(grads, state.ef_memory if use_ef else None)
-                sent = jax.tree_util.tree_map(
-                    lambda g: jax.vmap(chain.compress)(g), g_eff
-                )
-                new_ef = (
-                    ef_residual(g_eff, sent, alphas,
-                                delivered=ds if use_net else None)
-                    if use_ef else state.ef_memory
-                )
+                with jax.named_scope("compress"):
+                    g_eff = ef_add(grads, state.ef_memory if use_ef else None)
+                    sent = jax.tree_util.tree_map(
+                        lambda g: jax.vmap(chain.compress)(g), g_eff
+                    )
+                    new_ef = (
+                        ef_residual(g_eff, sent, alphas,
+                                    delivered=ds if use_net else None)
+                        if use_ef else state.ef_memory
+                    )
             else:
                 sent, new_ef = grads, state.ef_memory
         elif hetero_dispatch in ("hybrid", "switch"):
@@ -677,14 +695,16 @@ def make_triggered_train_step(
                 # trigger's probe re-evaluation cannot fuse back into
                 # the loss computation anyway.
                 def agent_prologue(ab):
-                    main, g = grad_prologue(state.params, ab, False)
+                    with jax.named_scope("prologue"):
+                        main, g = grad_prologue(state.params, ab, False)
                     if not prologue_fns:
                         return main, g, None
-                    pre = jnp.stack([
-                        jnp.asarray(fn(state.params, g, ab, main),
-                                    jnp.float32)
-                        for fn in prologue_fns
-                    ])
+                    with jax.named_scope("probe"):
+                        pre = jnp.stack([
+                            jnp.asarray(fn(state.params, g, ab, main),
+                                        jnp.float32)
+                            for fn in prologue_fns
+                        ])
                     return main, g, pre
 
                 losses, grads, pres = batch_prologue(agent_prologue)(batch)
@@ -1015,11 +1035,13 @@ def make_triggered_train_step(
         # server can only average what arrived.  Channel-free paths bind
         # ``delivereds`` to the same traced value as ``alphas``, so this
         # line compiles exactly as the pre-channel ``masked_mean``.
-        agg = masked_mean(sent, delivereds)
-        updates, opt_state = optimizer.update(
-            agg, state.opt_state, state.params, state.step
-        )
-        params = tree_add_scaled(state.params, updates, 1.0)
+        with jax.named_scope("aggregate"):
+            agg = masked_mean(sent, delivereds)
+        with jax.named_scope("update"):
+            updates, opt_state = optimizer.update(
+                agg, state.opt_state, state.params, state.step
+            )
+            params = tree_add_scaled(state.params, updates, 1.0)
         # wire ratios against the gradients' NATIVE dtype width (int8 on
         # bf16 grads is 0.5, not fp32's 0.25) — all static at trace
         # time; the entry count prices fixed-payload sketch chains

@@ -14,9 +14,12 @@ asynchronously (JAX returns futures), the NEXT round's observation
 batch is sampled on the host while the device works, and only then are
 the finished round's metrics pulled — host-side sampling and telemetry
 ride inside the device step's shadow instead of serializing after it.
-The step donates its TrainState argument (``donate_argnums=(0,)``), so
-steady-state serving allocates no new state buffers on backends that
-support donation.
+Each stage of a round (sample, dispatch, wait, pull, rollup,
+checkpoint) runs inside a profiler span ``fleet.<stage>`` carrying the
+round index, and its seconds accumulate in the rollup's
+``stage_seconds``.  The step donates its TrainState argument
+(``donate_argnums=(0,)``), so steady-state serving allocates no new
+state buffers on backends that support donation.
 
 Run modes:
 
@@ -40,6 +43,7 @@ arms a :class:`Watchdog` that flags stalled device dispatch as a
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import threading
@@ -126,6 +130,33 @@ class Watchdog:
         if self._thread is not None:
             self._thread.join(5.0)
             self._thread = None
+
+
+class _StageClock:
+    """Times the stages of served rounds.
+
+    ``with clock(stage, k):`` opens a profiler span ``fleet.<stage>``
+    carrying the round index (``round=k``) and adds the block's
+    ``perf_counter`` time to the stage's running total; :meth:`take`
+    hands the totals over and starts new ones.  The spans land in the
+    same trace as the device's ops (an inactive profiler makes them
+    near-free); the totals feed the rollup's ``stage_seconds``.
+    """
+
+    def __init__(self):
+        self._seconds: dict = {}
+
+    @contextlib.contextmanager
+    def __call__(self, stage: str, k: int):
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation(f"fleet.{stage}", round=k):
+            yield
+        self._seconds[stage] = (self._seconds.get(stage, 0.0)
+                                + time.perf_counter() - t)
+
+    def take(self) -> dict:
+        out, self._seconds = self._seconds, {}
+        return out
 
 
 class FleetSession:
@@ -241,18 +272,27 @@ class FleetSession:
         if opts.watchdog_timeout > 0:
             self._watchdog = Watchdog(self.rollup, opts.watchdog_timeout)
             self._watchdog.start()
+        stage = _StageClock()
         try:
-            batch = self._batch_fn(jax.random.fold_in(self._key, k))
+            with stage("sample", k):
+                batch = self._batch_fn(jax.random.fold_in(self._key, k))
             while not self._stop.is_set() and (target == 0 or k < target):
                 # 1. dispatch round k (async — returns device futures)
-                self._state, metrics = self._step(self._state, batch)
+                with stage("dispatch", k):
+                    self._state, metrics = self._step(self._state, batch)
                 # 2. sample round k+1's observations in the device's shadow
                 if target == 0 or k + 1 < target:
-                    batch = self._batch_fn(
-                        jax.random.fold_in(self._key, k + 1))
-                # 3. pull round k's metrics (blocks on the device), roll up
-                metrics = jax.device_get(metrics)
-                self.rollup.update(metrics)
+                    with stage("sample", k + 1):
+                        batch = self._batch_fn(
+                            jax.random.fold_in(self._key, k + 1))
+                # 3. wait for round k on the device, pull its metrics
+                # (the transfers alone), roll up
+                with stage("wait", k):
+                    jax.block_until_ready(metrics)
+                with stage("pull", k):
+                    metrics = jax.device_get(metrics)
+                with stage("rollup", k):
+                    self.rollup.update(metrics)
                 if self._watchdog is not None:
                     self._watchdog.beat()
                 if self._on_round is not None:
@@ -261,7 +301,9 @@ class FleetSession:
                 self._round = k
                 if (opts.ckpt_dir and opts.ckpt_every > 0
                         and (k - start) % opts.ckpt_every == 0):
-                    self.checkpoint()
+                    with stage("checkpoint", k - 1):
+                        self.checkpoint()
+                self.rollup.record_stage_seconds(stage.take())
         finally:
             if self._watchdog is not None:
                 self._watchdog.stop()

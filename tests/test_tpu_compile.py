@@ -91,3 +91,30 @@ def test_swa_attention_compiles_at_real_window(one_chip):
     compiled = swa_attention_kernel.lower(
         q, kv, kv, window=4096, bq=128, bk=128, interpret=False).compile()
     _assert_kernel(compiled)
+
+
+def test_gain_reduce_keeps_its_name_under_the_probe_scope(one_chip):
+    """The chip benchmark finds the kernel's device events by
+    ``gain_reduce_kernel`` in the op's name; the train step's ``probe``
+    scope, under the per-agent ``vmap``, must leave it there."""
+    import re
+
+    from repro.comm.policy import CommPolicy
+
+    trig = CommPolicy.parse_one(
+        "grad_norm(mu=1.0,kernel=true)").build_trigger()
+    grads = {"w": _sds((2, 4096, 64), jnp.float32, one_chip),
+             "b": _sds((2, 64), jnp.float32, one_chip)}
+
+    def train_step(grads):
+        def per_agent(g):
+            with jax.named_scope("probe"):
+                return trig.prologue(None, g, None, None)
+
+        return jax.vmap(per_agent)(grads)
+
+    # the kernel picks Mosaic over interpret mode from the default device
+    with jax.default_device(next(iter(one_chip.device_set))):
+        compiled = jax.jit(train_step).lower(grads).compile()
+    _assert_kernel(compiled)
+    assert re.search(r"%\S*gain_reduce_kernel\S* = ", compiled.as_text())

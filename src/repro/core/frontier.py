@@ -113,11 +113,12 @@ def budget_scales(targets, base: float) -> jnp.ndarray:
     return jnp.asarray(targets, jnp.float32) / jnp.float32(base)
 
 
-def _batch_fn_arity(batch_fn: Callable) -> int:
+def batch_fn_arity(batch_fn: Callable) -> int:
     """1 for the classic ``batch_fn(round_key)``, 2 for the
     round-indexed ``batch_fn(round_key, step)`` form (drifting-target
-    data modes need the round number to evaluate the drift inside the
-    scan).  Uninspectable callables default to the 1-arg contract."""
+    data modes and agent fault schedules need the round number inside
+    the compiled program).  Uninspectable callables default to the
+    1-arg contract."""
     try:
         params = inspect.signature(batch_fn).parameters
     except (TypeError, ValueError):
@@ -259,7 +260,7 @@ def run_frontier(
         rules=rules,
         churn=churn,
     )
-    arity = _batch_fn_arity(batch_fn)
+    arity = batch_fn_arity(batch_fn)
 
     def _xs(key):
         keys = jax.random.split(key, steps)

@@ -37,8 +37,6 @@ import sys
 import time
 from typing import Callable, Optional, Sequence
 
-import numpy as np
-
 
 # ----------------------------------------------------------------------
 # agent crash / flap schedules
@@ -66,56 +64,57 @@ class AgentFault:
     duration: int = 0
     period: int = 0
 
-    def down(self, round_index: int) -> bool:
-        if round_index < self.start:
-            return False
+    def down(self, round_index):
+        """Whether the agent is down in round ``round_index``: a bool
+        for an int, a traced bool for a traced round index."""
+        started = round_index >= self.start
         if self.period > 0:
-            return (round_index - self.start) % self.period < max(
-                self.duration, 1)
+            return started & ((round_index - self.start) % self.period
+                              < max(self.duration, 1))
         if self.duration == 0:
-            return True  # permanent crash
-        return round_index < self.start + self.duration
+            return started  # permanent crash
+        return started & (round_index < self.start + self.duration)
 
 
-def fault_mask(faults: Sequence[AgentFault], num_agents: int,
-               round_index: int) -> np.ndarray:
-    """float32 ``(num_agents,)`` activity mask (1 = up) for one round."""
-    mask = np.ones(num_agents, dtype=np.float32)
+def fault_mask(faults: Sequence[AgentFault], num_agents: int, round_index):
+    """float32 ``(num_agents,)`` activity mask (1 = up) for one round;
+    ``round_index`` may be traced."""
+    import jax.numpy as jnp
+
+    mask = jnp.ones(num_agents, jnp.float32)
     for f in faults:
-        if 0 <= f.agent < num_agents and f.down(round_index):
-            mask[f.agent] = 0.0
+        if 0 <= f.agent < num_agents:
+            mask = mask.at[f.agent].multiply(
+                jnp.where(f.down(round_index), 0.0, 1.0))
     return mask
 
 
 class FaultInjector:
-    """``batch_fn`` wrapper applying an :class:`AgentFault` schedule.
+    """Round-indexed ``batch_fn`` wrapper applying an :class:`AgentFault`
+    schedule.
 
-    Each call zeroes the leading (agent) axis rows of every batch leaf
-    for agents down this round, then advances an internal round
-    counter — construct with ``start_round`` when wrapping a resumed
-    session so the schedule stays aligned with the lineage's absolute
-    round index.
+    ``injector(round_key, k)`` draws ``batch_fn(round_key)`` and zeroes
+    the leading (agent) axis rows of every batch leaf for agents down in
+    round ``k``.  It is a pure function of its arguments, so a
+    :class:`~repro.launch.session.FleetSession` compiles it into its
+    round sampler with ``k`` traced, and a resumed session stays aligned
+    with the lineage's absolute round index.
     """
 
     def __init__(self, batch_fn: Callable, faults: Sequence[AgentFault],
-                 num_agents: int, *, start_round: int = 0):
+                 num_agents: int):
         self._batch_fn = batch_fn
         self.faults = tuple(faults)
         self.num_agents = num_agents
-        self._round = start_round
 
-    def __call__(self, key):
+    def __call__(self, key, round_index):
         import jax
 
         batch = self._batch_fn(key)
-        mask = fault_mask(self.faults, self.num_agents, self._round)
-        self._round += 1
-        if mask.min() >= 1.0:
-            return batch
-        m = np.asarray(mask)
+        mask = fault_mask(self.faults, self.num_agents, round_index)
         return jax.tree_util.tree_map(
-            lambda x: x * m.reshape((self.num_agents,)
-                                    + (1,) * (x.ndim - 1)).astype(x.dtype),
+            lambda x: x * mask.reshape((self.num_agents,)
+                                       + (1,) * (x.ndim - 1)).astype(x.dtype),
             batch)
 
 

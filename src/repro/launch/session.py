@@ -11,9 +11,14 @@ that HTTP scrapes and file sinks read while training runs.
 
 Overlap discipline (the double buffer): the jitted step is dispatched
 asynchronously (JAX returns futures), the NEXT round's observation
-batch is sampled on the host while the device works, and only then are
-the finished round's metrics pulled — host-side sampling and telemetry
-ride inside the device step's shadow instead of serializing after it.
+batch is drawn while the device works, and only then are the finished
+round's metrics pulled — sampling and telemetry ride inside the device
+step's shadow instead of serializing after it.  The round sampler is
+compiled once per session (``jit(sample_round)`` over the base key and
+the traced round index), so drawing a round is one call to a cached
+program that queues behind the step, not an eager trace of the
+sampler's ops; ``batch_fn`` must therefore be a pure JAX function of
+its arguments.
 Each stage of a round (sample, dispatch, wait, pull, rollup,
 checkpoint) runs inside a profiler span ``fleet.<stage>`` carrying the
 round index, and its seconds accumulate in the rollup's
@@ -55,6 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.comm.rollup import CommRollup
+from repro.core.frontier import batch_fn_arity
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,8 +177,14 @@ class FleetSession:
     state:
         Initial TrainState (``init_train_state``).
     batch_fn:
-        ``batch_fn(key) -> batch`` — one round's per-agent observation
-        batch; called on the host with a per-round fold of ``key``.
+        ``batch_fn(round_key) -> batch`` — one round's per-agent
+        observation batch from ``fold_in(key, k)``; a two-argument
+        ``batch_fn(round_key, k)`` also receives the absolute round
+        index ``k`` (fault schedules, drifting targets).  It must be a
+        pure JAX function of its arguments: the session compiles it
+        once, with ``k`` traced, so Python side effects run only while
+        it traces and the same ``(key, k)`` always gives the same batch
+        (which resume bit-equality relies on).
     rollup:
         The :class:`CommRollup` every round's metrics stream into.
     key:
@@ -194,6 +206,16 @@ class FleetSession:
         self._step = jax.jit(step_fn, donate_argnums=(0,))
         self._state = state
         self._batch_fn = batch_fn
+        with_round = batch_fn_arity(batch_fn) == 2
+
+        # the key is an argument, not a closure: a resume replaces it
+        def sample_round(key, k):
+            round_key = jax.random.fold_in(key, k)
+            if with_round:
+                return batch_fn(round_key, k)
+            return batch_fn(round_key)
+
+        self._sample = jax.jit(sample_round)
         self.rollup = rollup
         self._key = key if key is not None else jax.random.key(0)
         self._on_round = on_round
@@ -275,16 +297,16 @@ class FleetSession:
         stage = _StageClock()
         try:
             with stage("sample", k):
-                batch = self._batch_fn(jax.random.fold_in(self._key, k))
+                batch = self._sample(self._key, k)
             while not self._stop.is_set() and (target == 0 or k < target):
                 # 1. dispatch round k (async — returns device futures)
                 with stage("dispatch", k):
                     self._state, metrics = self._step(self._state, batch)
                 # 2. sample round k+1's observations in the device's shadow
+                # (one dispatch, queued behind step k)
                 if target == 0 or k + 1 < target:
                     with stage("sample", k + 1):
-                        batch = self._batch_fn(
-                            jax.random.fold_in(self._key, k + 1))
+                        batch = self._sample(self._key, k + 1)
                 # 3. wait for round k on the device, pull its metrics
                 # (the transfers alone), roll up
                 with stage("wait", k):

@@ -25,6 +25,7 @@ from repro.core.api import (
     init_train_state,
     make_triggered_train_step,
 )
+from repro.launch import compile_cache
 from repro.launch.faults import AgentFault, FaultInjector, fault_mask
 from repro.launch.session import FleetSession, SessionOptions, Watchdog
 from repro.optim import optimizers as opt_lib
@@ -175,15 +176,14 @@ def test_extra_metadata_roundtrip(tmp_path):
 # ----------------------------------------------------------------------
 
 
-def _session(spec, options=None, on_round=None, batch_wrap=None):
+def _session(spec, options=None, on_round=None, batch_fn=_batch, key=7):
     cfg = TrainConfig(lr=0.1, optimizer="sgd", num_agents=M, comm=spec)
     opt = opt_lib.from_config(cfg)
     step = make_triggered_train_step(
         _loss_fn, opt, cfg, options=StepOptions(agent_metrics=True))
     state = init_train_state({"w": jnp.zeros(N)}, opt, cfg)
-    batch_fn = batch_wrap(_batch) if batch_wrap else _batch
     return FleetSession(step, state, batch_fn, CommRollup(),
-                        key=jax.random.key(7), options=options,
+                        key=jax.random.key(key), options=options,
                         on_round=on_round)
 
 
@@ -211,6 +211,52 @@ def test_session_kill_resume_bit_equal(tmp_path):
                for k in before["counters"])
     # the untouched reference exports no restart/degradation fields
     assert "restarts" not in ref.rollup.snapshot()
+
+
+def _record_batches(sess, batches):
+    """Append ``(round, batch)`` for every batch the session's round
+    sampler hands its step."""
+    sample = sess._sample
+
+    def recorded(key, k):
+        batch = sample(key, k)
+        batches.append((k, batch))
+        return batch
+
+    sess._sample = recorded
+
+
+@pytest.mark.parametrize("faults", [(), (AgentFault(agent=2, start=3),)],
+                         ids=["plain", "agent_fault"])
+def test_session_compiles_sampler_once_across_resume(tmp_path, faults):
+    """Each session compiles its round sampler once, with the round
+    index traced and the base key an argument: over 5 rounds, a
+    checkpoint, a resume and 3 more rounds, round k's batch is
+    ``jax.jit(batch_fn)`` on ``fold_in(key, k)``, bit for bit."""
+    batch_fn = FaultInjector(_batch, faults, M) if faults else _batch
+    compile_cache.record_compiles()
+    opts = SessionOptions(ckpt_dir=str(tmp_path))
+    batches = []
+    # the resumed session's own key (99) must give way to the restored 7
+    for key, rounds in ((7, 5), (99, 3)):
+        sess = _session(SLOT_SPECS["ef"], options=opts, batch_fn=batch_fn,
+                        key=key)
+        _record_batches(sess, batches)
+        before = len(compile_cache.compile_events())
+        sess.run(rounds)
+        sess.checkpoint()
+        names = [e.name for e in compile_cache.compile_events()[before:]]
+        assert names.count("jit(sample_round)") == 1, names
+
+    assert [k for k, _ in batches] == list(range(8))
+    ref = jax.jit(batch_fn)
+    for k, got in batches:
+        round_key = jax.random.fold_in(jax.random.key(7), k)
+        want = ref(round_key, k) if faults else ref(round_key)
+        assert _leaves_equal(got, want), k
+    if faults:  # agent 2 is down from round 3 on
+        assert np.abs(np.asarray(batches[2][1][0][2])).max() > 0
+        assert np.abs(np.asarray(batches[6][1][0][2])).max() == 0
 
 
 def test_session_no_resume_starts_fresh(tmp_path):
@@ -314,9 +360,9 @@ def test_agent_fault_schedules():
 
 def test_fault_injector_zeroes_downed_rows():
     inj = FaultInjector(_batch, [AgentFault(agent=2, start=1)], M)
-    xs0, _ = inj(jax.random.key(0))           # round 0: everyone up
+    xs0, _ = inj(jax.random.key(0), 0)        # round 0: everyone up
     assert np.abs(np.asarray(xs0[2])).max() > 0
-    xs1, ys1 = inj(jax.random.key(1))         # round 1: agent 2 down
+    xs1, ys1 = inj(jax.random.key(1), 1)      # round 1: agent 2 down
     assert np.abs(np.asarray(xs1[2])).max() == 0
     assert np.abs(np.asarray(ys1[2])).max() == 0
     assert np.abs(np.asarray(xs1[1])).max() > 0

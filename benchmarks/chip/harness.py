@@ -5,8 +5,9 @@ metric sits in a file of its own, found by the name ``BENCHMARK.json``
 gives it:
 
 * ``configs/<config>.json`` — the configuration as it is run; its
-  ``kind`` names the runner ``kinds/<kind>.py`` and its plain reference
-  is ``configs/<config>.ref.py``.
+  ``kind`` names the runner ``kinds/<kind>.py``, and its plain reference
+  lies beside the file ``BENCHMARK.json`` names, ``<file>.json`` →
+  ``<file>.ref.py``.
 * ``traffic/<mix>.json`` — read by the one generator, ``traffic.py``.
 * ``metrics/<metric>.py`` — a reader ``read(ctx) -> float | None``.
 * ``limits/<cell>.json`` — the limit of each number that decides
@@ -39,7 +40,8 @@ def _module(path: Path) -> ModuleType:
     """Import a file of the benchmark by its path (names hold ``.``/``-``)."""
     if not path.is_file():
         raise FileNotFoundError(f"missing benchmark file {path}")
-    key = "chipbench_" + re.sub(r"\W", "_", str(path.relative_to(HERE)))
+    name = path.relative_to(HERE) if path.is_relative_to(HERE) else path
+    key = "chipbench_" + re.sub(r"\W", "_", str(name))
     if key in sys.modules:
         return sys.modules[key]
     spec = importlib.util.spec_from_file_location(key, path)
@@ -57,8 +59,11 @@ def runner(kind: str) -> ModuleType:
     return _module(HERE / "kinds" / f"{kind}.py")
 
 
-def reference(config: str) -> ModuleType:
-    return _module(HERE / "configs" / f"{config}.ref.py")
+def reference(config_file: Path) -> ModuleType:
+    """The plain reference beside a configuration's file."""
+    path = Path(config_file)
+    return _module(path.with_name(path.name.removesuffix(".json")
+                                  + ".ref.py"))
 
 
 def resolve(workload: str, bench: Optional[dict] = None,
@@ -73,7 +78,8 @@ def resolve(workload: str, bench: Optional[dict] = None,
     cell = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     conf = configs[cell["config"]]
-    cfg = json.loads((Path(root) / conf["file"]).read_text())
+    cfg_file = (Path(root) / conf["file"]).resolve()
+    cfg = json.loads(cfg_file.read_text())
 
     def applies(m):
         return "workloads" not in m or workload in m["workloads"]
@@ -90,7 +96,7 @@ def resolve(workload: str, bench: Optional[dict] = None,
         per_layer=[m for m in bench["per_layer"] if applies(m)],
         limits=json.loads(limits_path.read_text()),
         kind=runner(cfg["kind"]),
-        ref=reference(cell["config"]),
+        ref=reference(cfg_file),
     )
 
 

@@ -14,6 +14,16 @@ mean trigger gain (the gain-reduce kernel's squared norm, or the
 lookahead probe's loss change), a controller's per-agent state after
 each step, the norm of the first aggregate the optimizer gets, and per
 leaf the norm of the parameters' change over those steps.
+
+Everything particular to a model comes from its configuration's own
+files, so a new architecture is added as files and no code here
+changes.  ``configs/<name>.json`` names the program's architecture
+(``program.arch``), the attributes the program's config must show
+(``program.expect``), where each of the reference's leaves sits in the
+program's parameter tree (``leaves``) and the CPU tests' cut (``toy``).
+The reference module beside it, ``configs/<name>.ref.py``, gives
+``init_params``, ``loss``, ``train`` (``lm_reference.train`` of that
+loss), ``train_flops_per_token`` and ``param_count``.
 """
 from __future__ import annotations
 
@@ -23,22 +33,20 @@ import numpy as np
 
 CHECK_STEPS = 3
 STEP_MODULE = r"^jit_train_step\("
-# the program's parameter tree, keyed by the reference's leaf names
-PROGRAM_LEAVES = {
-    "embed": ("embedding",), "final_norm": ("final_norm",),
-    "attn_norm": ("blocks", "ln_attn"), "ffn_norm": ("blocks", "ln_ff"),
-    "wq": ("blocks", "attn", "wq"), "wk": ("blocks", "attn", "wk"),
-    "wv": ("blocks", "attn", "wv"), "wo": ("blocks", "attn", "wo"),
-    "w_gate": ("blocks", "mlp", "w_gate"), "w_up": ("blocks", "mlp", "w_up"),
-    "w_down": ("blocks", "mlp", "w_down"),
-}
 # triggers whose gain needs one more forward pass (the lookahead probe)
 PROBE_TRIGGERS = ("gain_lookahead", "budget_dual", "budget_window")
 
 
-def to_program(canon: dict) -> dict:
+def trigger_of(comm: str) -> str:
+    """The trigger's name in a ``comm`` spec string."""
+    return comm.split("(")[0].split("|")[0].strip()
+
+
+def to_program(canon: dict, leaves: dict) -> dict:
+    """The reference's flat leaves placed at their paths in the program's
+    tree (the configuration's ``leaves``)."""
     out: dict = {}
-    for name, path in PROGRAM_LEAVES.items():
+    for name, path in leaves.items():
         node = out
         for key in path[:-1]:
             node = node.setdefault(key, {})
@@ -46,9 +54,9 @@ def to_program(canon: dict) -> dict:
     return out
 
 
-def from_program(params: dict) -> dict:
+def from_program(params: dict, leaves: dict) -> dict:
     out = {}
-    for name, path in PROGRAM_LEAVES.items():
+    for name, path in leaves.items():
         node = params
         for key in path:
             node = node[key]
@@ -57,33 +65,29 @@ def from_program(params: dict) -> dict:
 
 
 def program_config(cfg: dict):
-    """The program's model config, checked against the file's widths."""
+    """The program's model config, checked against the file: each
+    attribute ``program.expect`` names must equal the file key it gives,
+    or the fixed ``{"value": ...}``."""
     from repro.configs import get_config, reduced
 
-    mc = get_config(cfg["program"]["arch"])
-    if cfg["program"].get("reduced"):
+    prog = cfg["program"]
+    mc = get_config(prog["arch"])
+    if prog.get("reduced"):
         # the repository's smoke-test cut of the same family (tests only)
         mc = reduced(mc)
-    got = {"hidden_size": mc.d_model, "intermediate_size": mc.d_ff,
-           "num_hidden_layers": mc.num_layers,
-           "num_attention_heads": mc.num_heads,
-           "num_key_value_heads": mc.num_kv_heads, "head_dim": mc.head_dim_,
-           "vocab_size": mc.vocab_size, "rms_norm_eps": mc.norm_eps,
-           "rope_theta": mc.rope_theta,
-           "tie_word_embeddings": mc.tie_embeddings}
-    want = {k: cfg[k] for k in got}
-    if got != want or mc.arch_type != "dense" or mc.qk_norm or mc.swa_window:
+    got = {attr: getattr(mc, attr) for attr in prog["expect"]}
+    want = {attr: src["value"] if isinstance(src, dict) else cfg[src]
+            for attr, src in prog["expect"].items()}
+    if got != want:
         raise ValueError(f"the program's {mc.name} differs from the "
                          f"configuration file: {got} != {want}")
     return mc
 
 
-def flops_per_token(cfg: dict, comm: str) -> float:
-    from benchmarks.chip.counts import llama_train_flops_per_token
-
-    probe = comm.split("(")[0].split("|")[0].strip() in PROBE_TRIGGERS
-    return llama_train_flops_per_token(cfg, cfg["seq_len"],
-                                       extra_forwards=int(probe))
+def flops_per_token(cell) -> float:
+    probe = trigger_of(cell.mix["comm"]) in PROBE_TRIGGERS
+    return cell.ref.train_flops_per_token(cell.cfg, cell.cfg["seq_len"],
+                                          int(probe))
 
 
 def leaf_norms(a: dict, b: dict) -> dict:
@@ -150,11 +154,12 @@ def build(cell, pseed: int):
                       optimizer=tr["optimizer"], lr=tr["lr"],
                       agents=tr["agents"])
     jitted, *_ = S.build_train_step(mesh, plan, compute_dtype=tr["dtype"])
-    params = to_program(cell.ref.init_params(cfg, pseed, jnp.dtype(tr["dtype"])))
+    params = to_program(cell.ref.init_params(cfg, pseed, jnp.dtype(tr["dtype"])),
+                        cfg["leaves"])
     state = init_train_state(params, opt_lib.from_config(plan.train_cfg),
                              plan.train_cfg)
-    batches = token_batches(pseed, steps=mix["tokens"]["pool"],
-                            agents=tr["agents"], batch=tr["batch_per_agent"],
+    batches = token_batches(pseed, mix["tokens"], agents=tr["agents"],
+                            batch=tr["batch_per_agent"],
                             seq_len=cfg["seq_len"], vocab=cfg["vocab_size"])
     compiled = jitted.lower(state, batches[0]).compile()
     return compiled, state, batches
@@ -207,12 +212,10 @@ def run(cell, *, seed: int, seconds: float, t_start: float, trace_dir=None):
            "gain": [float(m["mean_gain"]) for m in first[:CHECK_STEPS]],
            "ctrl": ctrl,
            "dparam_leaf_norm": leaf_norms(
-               from_program(state_checked.params), canon0)}
+               from_program(state_checked.params, cfg["leaves"]), canon0)}
     del compiled, state, state_checked, m, pending
     ref = cell.ref.train(cfg, canon0, batches[:CHECK_STEPS], mix["comm"],
                          CHECK_STEPS)
-    from benchmarks.chip.counts import llama_param_count
-
     return {
         "setup_s": t0 - t_start,
         "window_s": seconds,
@@ -223,9 +226,9 @@ def run(cell, *, seed: int, seconds: float, t_start: float, trace_dir=None):
         "memory_peak_bytes": mem,
         "checks": compare(got, ref),
         "tokens_per_step": tr["agents"] * tr["batch_per_agent"] * cfg["seq_len"],
-        "flops_per_token": flops_per_token(cfg, mix["comm"]),
+        "flops_per_token": flops_per_token(cell),
         "step_module": STEP_MODULE,
-        "gain_reduce": ({"elements": llama_param_count(cfg),
+        "gain_reduce": ({"elements": cell.ref.param_count(cfg),
                          "agents": tr["agents"]}
                         if "kernel=true" in mix["comm"].replace(" ", "")
                         else None),
@@ -243,8 +246,8 @@ def controls(cell, seed: int, seconds: float):
     tr = cfg["train"]
     pseed = program_seed(seed)
     canon0 = ref_mod.init_params(cfg, pseed, jnp.dtype(tr["dtype"]))
-    batches = token_batches(pseed, steps=mix["tokens"]["pool"],
-                            agents=tr["agents"], batch=tr["batch_per_agent"],
+    batches = token_batches(pseed, mix["tokens"], agents=tr["agents"],
+                            batch=tr["batch_per_agent"],
                             seq_len=cfg["seq_len"],
                             vocab=cfg["vocab_size"])[:CHECK_STEPS]
 
@@ -260,9 +263,10 @@ def controls(cell, seed: int, seconds: float):
     yield "fault:no_exchange", compare(train(solo), ref)
     # the trigger's own answer altered where it is made: the kernel's
     # squared norm halved, or the lookahead probe's gain lost
-    if mix["comm"].startswith("grad_norm"):
+    trig = trigger_of(mix["comm"])
+    if trig == "grad_norm":
         yield "fault:gsq_halved", compare(train(batches, gain_scale=0.5), ref)
-    else:
+    elif trig in PROBE_TRIGGERS:
         yield "fault:probe_gain_zeroed", compare(
             train(batches, gain_scale=0.0), ref)
     yield "fault:loss_altered", compare(

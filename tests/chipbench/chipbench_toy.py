@@ -1,8 +1,10 @@
 """Helpers the chip-benchmark tests share: the benchmark's cells at a
-size the CPU test run can hold, and a timer for ``run_cell``."""
+size the CPU test run can hold, the faults each cell can have, and a
+timer for ``run_cell``."""
 from __future__ import annotations
 
 import copy
+import json
 import sys
 import time
 from pathlib import Path
@@ -13,25 +15,69 @@ if str(ROOT) not in sys.path:
 
 from benchmarks.chip import harness  # noqa: E402
 
-# the smoke-test cut the repository applies to smollm-135m
-# (repro.configs.reduced): 2 layers, d_model 256, 4 heads over 2 KV
-# heads of 64, d_ff 512, vocab 512; sequences of 32 tokens
-TOY_LM = dict(hidden_size=256, intermediate_size=512, num_hidden_layers=2,
-              num_attention_heads=4, num_key_value_heads=2, head_dim=64,
-              vocab_size=512, seq_len=32)
+BENCH = harness.load_benchmark()
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+# the faults every cell of a runner kind can have, planted in the program
+KIND_FAULTS = {
+    "fleet_serve": {"half_batch", "state_unchanged", "answer_altered",
+                    "mispriced_tier"},
+    "lm_train": {"half_batch", "no_exchange", "loss_altered",
+                 "state_unchanged"},
+}
+# an LM cell's trigger adds the fault of its own answer: the gain-reduce
+# kernel's squared norm halved, or the lookahead probe's gain lost
+TRIGGER_FAULTS = {"grad_norm": "gsq_halved",
+                  "gain_lookahead": "probe_gain_zeroed",
+                  "budget_dual": "probe_gain_zeroed",
+                  "budget_window": "probe_gain_zeroed"}
+
+
+def _files(workload: str) -> tuple:
+    """The configuration and the mix ``workload`` names, as data."""
+    cell = next(w for w in BENCH["workloads"] if w["name"] == workload)
+    conf = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
+    mix = harness.HERE / "traffic" / f"{cell['traffic']}.json"
+    return (json.loads((ROOT / conf["file"]).read_text()),
+            json.loads(mix.read_text()))
+
+
+def kind_of(workload: str) -> str:
+    return _files(workload)[0]["kind"]
+
+
+def faults(workload: str) -> set:
+    """The faults ``workload`` can have: its kind's, and for an LM cell
+    its trigger's, read from its mix's ``comm``."""
+    cfg, mix = _files(workload)
+    out = set(KIND_FAULTS[cfg["kind"]])
+    if cfg["kind"] == "lm_train":
+        trig = mix["comm"].split("(")[0].split("|")[0].strip()
+        out |= {TRIGGER_FAULTS[trig]} if trig in TRIGGER_FAULTS else set()
+    return out
+
+
+def cells_of(kind: str) -> list:
+    return [w for w in CELLS if kind_of(w) == kind]
+
+
+def toy_cut(cell):
+    """``cell`` cut to its configuration's ``toy`` size, where it has one
+    (the fleet runs at its own size, which is small)."""
+    toy = cell.cfg.get("toy")
+    if toy:
+        cfg = copy.deepcopy(cell.cfg)
+        cfg.update(toy["sizes"])
+        cfg["program"] = dict(cfg["program"], **toy["program"])
+        cell.cfg = cfg
+        cell.mix = dict(cell.mix, tokens=dict(cell.mix["tokens"],
+                                              pool=toy["pool"]))
+    return cell
 
 
 def toy_cell(workload: str):
-    """``workload`` resolved by name; a language-model cell is cut to the
-    toy size (the fleet runs at its own size, which is small)."""
-    cell = harness.resolve(workload)
-    if cell.cfg["kind"] == "lm_train":
-        cfg = copy.deepcopy(cell.cfg)
-        cfg.update(TOY_LM)
-        cfg["program"] = dict(cfg["program"], reduced=True)
-        cell.cfg = cfg
-        cell.mix = dict(cell.mix, tokens={"dist": "uniform", "pool": 8})
-    return cell
+    """``workload`` resolved by name and cut to its toy size."""
+    return toy_cut(harness.resolve(workload))
 
 
 def run(cell, seed: int = 2**31 + 17, seconds: float = 1.0,

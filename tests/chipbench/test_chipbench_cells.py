@@ -1,9 +1,8 @@
-"""Each cell's run path end to end on the CPU, called as a function:
-the language-model cell at the toy size, the fleet at its own size."""
+"""Each cell of ``BENCHMARK.json``, its run path end to end on the CPU,
+called as a function: a language-model cell at its configuration's toy
+size, the fleet at its own size."""
 import pytest
-from chipbench_toy import run, toy_cell
-
-CELLS = ["fleet_m64_serve", "smollm135m_gradnorm", "smollm135m_budget"]
+from chipbench_toy import CELLS, run, toy_cell
 
 
 @pytest.mark.parametrize("workload", CELLS)

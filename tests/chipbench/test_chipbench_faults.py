@@ -6,7 +6,7 @@ program is broken by patching what the timed path calls.
 """
 import jax.numpy as jnp
 import pytest
-from chipbench_toy import run, toy_cell
+from chipbench_toy import CELLS, cells_of, faults, run, toy_cell
 
 import repro.comm.triggers as triggers
 import repro.core.api as api
@@ -75,8 +75,11 @@ FLEET_FAULTS = {
 @pytest.mark.parametrize("fault", sorted(FLEET_FAULTS))
 def test_fleet_fault_is_not_correct(monkeypatch, fault):
     FLEET_FAULTS[fault](monkeypatch)
-    res = run(toy_cell("fleet_m64_serve"), seconds=1.0)
-    assert res["correct"] is False, res["checks"]
+    workloads = cells_of("fleet_serve")
+    assert workloads
+    for workload in workloads:
+        res = run(toy_cell(workload), seconds=1.0)
+        assert res["correct"] is False, (workload, res["checks"])
 
 
 # -- language-model training ----------------------------------------------
@@ -137,11 +140,9 @@ LM_FAULTS = {
     "gsq_halved": _lm_gsq_halved,
     "probe_gain_zeroed": _lm_probe_gain_zeroed,
 }
-# the trigger's own fault is planted in the cell whose trigger it breaks
-LM_CASES = [("smollm135m_gradnorm", f) for f in sorted(LM_FAULTS)
-            if f != "probe_gain_zeroed"] + [
-    ("smollm135m_budget", "probe_gain_zeroed"),
-    ("smollm135m_budget", "state_unchanged")]
+# each LM cell with every fault it can have: the general ones, and its
+# trigger's own, planted in the cell whose trigger it breaks
+LM_CASES = [(w, f) for w in cells_of("lm_train") for f in sorted(faults(w))]
 
 
 @pytest.mark.parametrize("workload,fault", LM_CASES)
@@ -154,9 +155,7 @@ def test_lm_fault_is_not_correct(monkeypatch, workload, fault):
 # -- the control: the reference one precision below the configuration ----
 
 
-@pytest.mark.parametrize("workload", ["fleet_m64_serve",
-                                      "smollm135m_gradnorm",
-                                      "smollm135m_budget"])
+@pytest.mark.parametrize("workload", CELLS)
 def test_control_fails_a_limit(workload):
     cell = toy_cell(workload)
     side, checks = next(cell.kind.controls(cell, 3, 2.0))
@@ -164,24 +163,14 @@ def test_control_fails_a_limit(workload):
     assert any(checks[k] > limit for k, limit in cell.limits.items()), checks
 
 
-FAULT_READERS = {
-    "smollm135m_gradnorm": {"half_batch", "no_exchange", "loss_altered",
-                            "state_unchanged", "gsq_halved"},
-    "smollm135m_budget": {"half_batch", "no_exchange", "loss_altered",
-                          "state_unchanged", "probe_gain_zeroed"},
-    "fleet_m64_serve": {"half_batch", "state_unchanged", "answer_altered",
-                        "mispriced_tier"},
-}
-
-
-@pytest.mark.parametrize("workload", sorted(FAULT_READERS))
+@pytest.mark.parametrize("workload", sorted(CELLS))
 def test_fault_readers_cover_the_faults(workload):
     # each fault the chip calibration reads fails one of the cell's limits
     cell = toy_cell(workload)
     read = dict(cell.kind.controls(cell, 3, 1.0))
     assert list(read)[0] == "control"
-    faults = {s.split(":")[1]: c for s, c in read.items() if ":" in s}
-    assert set(faults) >= FAULT_READERS[workload]
-    for name, checks in faults.items():
+    planted = {s.split(":")[1]: c for s, c in read.items() if ":" in s}
+    assert set(planted) >= faults(workload)
+    for name, checks in planted.items():
         assert not all(checks[k] <= lim for k, lim in cell.limits.items()), \
             (name, checks)

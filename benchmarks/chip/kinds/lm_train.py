@@ -214,6 +214,8 @@ def run(cell, *, seed: int, seconds: float, t_start: float, trace_dir=None):
            "dparam_leaf_norm": leaf_norms(
                from_program(state_checked.params, cfg["leaves"]), canon0)}
     del compiled, state, state_checked, m, pending
+    # the reference takes its initial weights from the host
+    canon0 = jax.device_get(canon0)
     ref = cell.ref.train(cfg, canon0, batches[:CHECK_STEPS], mix["comm"],
                          CHECK_STEPS)
     return {
@@ -238,6 +240,7 @@ def run(cell, *, seed: int, seconds: float, t_start: float, trace_dir=None):
 def controls(cell, seed: int, seconds: float):
     """What the control and the planted faults read, against the
     reference, on the cell's first steps: ``(side, checks)``."""
+    import jax
     import jax.numpy as jnp
 
     from benchmarks.chip.traffic import program_seed, token_batches
@@ -245,7 +248,8 @@ def controls(cell, seed: int, seconds: float):
     cfg, mix, ref_mod = cell.cfg, cell.mix, cell.ref
     tr = cfg["train"]
     pseed = program_seed(seed)
-    canon0 = ref_mod.init_params(cfg, pseed, jnp.dtype(tr["dtype"]))
+    canon0 = jax.device_get(ref_mod.init_params(cfg, pseed,
+                                                jnp.dtype(tr["dtype"])))
     batches = token_batches(pseed, mix["tokens"], agents=tr["agents"],
                             batch=tr["batch_per_agent"],
                             seq_len=cfg["seq_len"],

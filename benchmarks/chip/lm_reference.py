@@ -8,11 +8,22 @@ agent's gradient of its mean token cross-entropy, the trigger of the
 traffic's ``comm`` spec, per-tensor int8 compression with error
 feedback, the mean over transmitting agents (arXiv:2103.04140 eq. 10)
 and SGD, the parameters kept in the configuration's ``train.dtype``
-(each step's -lr·g is cast to that type and added there).
+(each step's -lr·g is rounded to that type and added there).
 
 Departures, on purpose: none in the mathematics.  Gradients, error
 feedback and the aggregate stay in float32, where the program keeps
 them in the parameters' type.
+
+What lives where, so that a model of about a billion parameters can be
+followed on one 16 GB chip: the device holds the parameters in
+``train.dtype``, one agent's f32 gradient, the lookahead probe's
+parameters in ``train.dtype`` while its loss runs, and the f32 casts of
+either only inside a jitted call: about 10 bytes a parameter, plus the
+loss's activations.  The host holds each agent's error-feedback memory,
+the running aggregate and the initial parameters.  The arithmetic and
+its order are those of the loop that kept every tree on the device; the
+step's rounding to ``train.dtype`` is a ``reduce_precision``, which the
+compiler keeps, where it may fold a pair of casts into none.
 
 ``mm`` is the one matmul a model's loss calls.  It runs at the highest
 precision, or with ``lowp=True`` as the control, one precision below a
@@ -24,6 +35,7 @@ finite value (so small gradients keep their digits, not flush to 0).
 from __future__ import annotations
 
 import functools
+import json
 import re
 
 import jax
@@ -87,12 +99,59 @@ def _int8(x):
     return jnp.clip(jnp.round(x / scale), -127, 127) * scale
 
 
+def _round_to(x, dtype):
+    """``x`` rounded to ``dtype``'s precision, kept in its own type: a
+    rounding the compiler keeps, where it may fold a pair of casts."""
+    fi = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(x, exponent_bits=fi.nexp,
+                                    mantissa_bits=fi.nmant)
+
+
+@functools.lru_cache(maxsize=None)
+def _wire(int8: bool):
+    @jax.jit
+    def wire(g, mem, alpha):
+        # one leaf: the payload the agent sends and its residual
+        g_eff = g if mem is None else g + mem
+        sent = _int8(g_eff) if int8 else g_eff
+        return sent, (g_eff - sent) * alpha
+    return wire
+
+
+@functools.lru_cache(maxsize=None)
+def _apply(lr: float, pdt):
+    @jax.jit
+    def apply(p, agg):
+        # SGD on parameters held in ``pdt``: the step -lr·g is rounded to
+        # the parameters' type and added there
+        return jax.tree_util.tree_map(
+            lambda a, b: (a.astype(jnp.float32) + _round_to(-lr * b, pdt)
+                          ).astype(pdt), p, agg)
+    return apply
+
+
+@functools.lru_cache(maxsize=None)
+def _losses(loss, cfg_json: str, lowp: bool):
+    """``value_and_grad`` of ``loss`` and ``loss`` itself, each taking the
+    parameters in their own type and casting them up inside the call."""
+    cfg = json.loads(cfg_json)
+
+    def up(p):
+        return jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), p)
+
+    vg = jax.jit(lambda p, t, y: jax.value_and_grad(
+        lambda q: loss(cfg, q, t, y, lowp))(up(p)))
+    f_loss = jax.jit(lambda p, t, y: loss(cfg, up(p), t, y, lowp))
+    return vg, f_loss
+
+
 def train(cfg: dict, loss, params0: dict, batches, comm: str, steps: int,
           lowp: bool = False, gain_scale: float = 1.0) -> dict:
     """``steps`` triggered steps from ``params0`` (in ``train.dtype``, the
-    layout ``loss`` reads) on ``batches`` (each ``{"tokens", "labels"}``
-    of shape ``(m, B, S)``); ``loss(cfg, p, tokens, labels, lowp)`` is
-    one agent's mean next-token cross-entropy.
+    layout ``loss`` reads; host arrays, so that the caller holds no copy
+    on the device) on ``batches`` (each ``{"tokens", "labels"}`` of shape
+    ``(m, B, S)``); ``loss(cfg, p, tokens, labels, lowp)`` is one agent's
+    mean next-token cross-entropy.
 
     Returns per step the mean agent loss, the mean agent trigger gain
     (``-lr·‖g‖²`` for ``grad_norm``, the lookahead probe's loss change
@@ -100,56 +159,46 @@ def train(cfg: dict, loss, params0: dict, batches, comm: str, steps: int,
     and, for a controller, each agent's row ``(λ, σ, ĝ)`` after the
     step; per leaf the norm of step 1's aggregate and of the parameters'
     change over all ``steps``.  ``gain_scale`` multiplies each gain as
-    it is computed (1 here; a planted fault changes it)."""
+    it is computed (1 here; a planted fault changes it).
+
+    Each jitted call casts the parameters up itself, and the gradient is
+    taken with respect to those f32 values.  The wire runs leaf by leaf:
+    the agent's memory comes in from the host, the int8 payload and the
+    residual are made, the residual goes back, and α·payload is added to
+    the running sum on the host, ``0 + α₀·x₀ + α₁·x₁ …``: the same f32
+    operations in the same order, with the products by α ∈ {0, 1} taken
+    exactly (a payload is added or left out).  The device divides the sum
+    by the transmitting count.  Jitted programs are made once a process,
+    for all calls with the same loss, configuration and wire."""
     pol = parse_comm(comm)
     if pol["stages"] not in ([], ["int8"]):
         raise ValueError(f"reference has no wire format {pol['stages']}")
     lr = float(cfg["train"]["lr"])
     pdt = jnp.dtype(cfg["train"]["dtype"])
     trig, args = pol["trigger"], pol["args"]
-    vg = jax.jit(jax.value_and_grad(
-        lambda p, t, y: loss(cfg, p, t, y, lowp)))
-    f_loss = jax.jit(lambda p, t, y: loss(cfg, p, t, y, lowp))
-    to32 = jax.jit(lambda t: jax.tree_util.tree_map(
-        lambda x: x.astype(jnp.float32), t))
+    f32 = jnp.float32
+    vg, f_loss = _losses(loss, json.dumps(cfg, sort_keys=True), lowp)
+    wire, apply = _wire(bool(pol["stages"])), _apply(lr, pdt)
 
-    @jax.jit
-    def wire(g, mem, alpha):
-        g_eff = g if mem is None else jax.tree_util.tree_map(jnp.add, g, mem)
-        sent = (jax.tree_util.tree_map(_int8, g_eff) if pol["stages"]
-                else g_eff)
-        resid = jax.tree_util.tree_map(lambda a, b: (a - b) * alpha, g_eff,
-                                       sent)
-        return sent, resid
-
-    @jax.jit
-    def apply(p32, agg):
-        # SGD on parameters held in ``pdt``: the step -lr·g is cast to
-        # the parameters' type and added there
-        new = jax.tree_util.tree_map(
-            lambda p, a: (p + (-lr * a).astype(pdt).astype(jnp.float32)
-                          ).astype(pdt), p32, agg)
-        return new, jax.tree_util.tree_map(lambda x: x.astype(jnp.float32),
-                                           new)
-
-    def probe_gain(p32, g, t, y, l0):
-        eps = lr
-        probe = jax.tree_util.tree_map(
-            lambda p, gg: (p - eps * gg).astype(pdt).astype(jnp.float32),
-            p32, g)
+    def probe_gain(p, g, t, y, l0):
+        # the lookahead probe: one SGD step of size lr, held in ``pdt``;
+        # made leaf by leaf, op by op, so that no compiler fuses the step
+        probe = {k: (p[k].astype(f32) - lr * g[k]).astype(pdt) for k in p}
         return float(f_loss(probe, t, y)) - l0
 
+    host0 = jax.device_get(params0)
+    shapes = {k: np.shape(v) for k, v in host0.items()}
+    params = jax.device_put(host0)
     agents = batches[0]["tokens"].shape[0]
-    p32 = to32(params0)
     mems = [None] * agents
     ctrl = [[float(args.get("lam0", 0.0)), 0.0, 0.0] for _ in range(agents)]
     out = {"loss": [], "gnorm": [], "gain": [], "ctrl": []}
     for s in range(steps):
         b = batches[s]
-        sents, alphas, losses, gains = [], [], [], []
+        acc, alphas, losses, gains = {}, [], [], []
         for i in range(agents):
             t, y = b["tokens"][i], b["labels"][i]
-            l0, g = vg(p32, t, y)
+            l0, g = vg(params, t, y)
             l0 = float(l0)
             losses.append(l0)
             if trig == "grad_norm":
@@ -159,7 +208,7 @@ def train(cfg: dict, loss, params0: dict, batches, comm: str, steps: int,
                 gains.append(-lr * gsq)
             elif trig == "budget_dual":
                 lam, sig, gmag = ctrl[i]
-                gain = gain_scale * probe_gain(p32, g, t, y, l0)
+                gain = gain_scale * probe_gain(params, g, t, y, l0)
                 gains.append(gain)
                 eta = float(args.get("eta", 0.5))
                 beta = float(args.get("beta", 0.1))
@@ -174,16 +223,30 @@ def train(cfg: dict, loss, params0: dict, batches, comm: str, steps: int,
                 gains.append(0.0)
             else:
                 raise ValueError(f"reference has no trigger {trig!r}")
-            sent, resid = wire(g, mems[i], jnp.float32(alpha))
-            if pol["ef"]:
-                mems[i] = resid
-            sents.append(sent)
+            mem, mems[i] = mems[i], ({} if pol["ef"] else None)
+            keys, wired = list(g), {}
+            for j, k in enumerate(keys + [None]):
+                if k is not None:
+                    # this leaf's wire runs while the one before comes home
+                    wired[k] = wire(g.pop(k), None if mem is None
+                                    else mem.pop(k), f32(alpha))
+                    for x in wired[k]:
+                        x.copy_to_host_async()
+                if j:
+                    done = keys[j - 1]
+                    sent, resid = wired.pop(done)
+                    if pol["ef"]:
+                        # a copy: a CPU device's array shares its buffer
+                        mems[i][done] = np.asarray(resid).copy()
+                    if alpha and done in acc:
+                        np.add(acc[done], np.asarray(sent), out=acc[done])
+                    elif alpha:
+                        acc[done] = np.asarray(sent).copy()
             alphas.append(alpha)
-            del g
         denom = max(sum(alphas), 1.0)
-        agg = jax.tree_util.tree_map(
-            lambda *xs: sum(a * x for a, x in zip(alphas, xs)) / denom, *sents)
-        del sents
+        agg = {k: jnp.asarray(acc.pop(k) if k in acc
+                              else np.zeros(shapes[k], np.float32)) / denom
+               for k in shapes}
         if s == 0:
             out["agg_leaf_norm"] = {k: float(jnp.linalg.norm(v))
                                     for k, v in agg.items()}
@@ -193,10 +256,10 @@ def train(cfg: dict, loss, params0: dict, batches, comm: str, steps: int,
         out["gain"].append(float(np.mean(gains)))
         if trig == "budget_dual":
             out["ctrl"].append([list(row) for row in ctrl])
-        params, p32 = apply(p32, agg)
+        params = apply(params, agg)
         del agg
-    p0 = to32(params0)
     out["dparam_leaf_norm"] = {
-        k: float(jnp.linalg.norm(params[k].astype(jnp.float32) - p0[k]))
+        k: float(jnp.linalg.norm(params[k].astype(f32)
+                                 - jnp.asarray(host0[k]).astype(f32)))
         for k in params}
     return out

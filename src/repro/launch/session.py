@@ -13,18 +13,24 @@ Overlap discipline (the double buffer): the jitted step is dispatched
 asynchronously (JAX returns futures), the NEXT round's observation
 batch is drawn while the device works, and only then are the finished
 round's metrics pulled — sampling and telemetry ride inside the device
-step's shadow instead of serializing after it.  The round sampler is
-compiled once per session (``jit(sample_round)`` over the base key and
-the traced round index), so drawing a round is one call to a cached
-program that queues behind the step, not an eager trace of the
-sampler's ops; ``batch_fn`` must therefore be a pure JAX function of
-its arguments.
+step's shadow instead of serializing after it.  The session jits the
+step so that a round's metrics come home as one flat buffer per dtype
+(:func:`pack_metrics`), whose copy to the host is started as the step
+is dispatched: the pull is one host transfer per dtype, not one per
+metric, and the dict handed to the rollup and ``on_round`` is the same
+tree of host numpy arrays ``jax.device_get`` would give.
+The round sampler is compiled once per session (``jit(sample_round)``
+over the base key and the traced round index), so drawing a round is
+one call to a cached program that queues behind the step, not an eager
+trace of the sampler's ops; ``batch_fn`` must therefore be a pure JAX
+function of its arguments.
 Each stage of a round (sample, dispatch, wait, pull, rollup,
 checkpoint) runs inside a profiler span ``fleet.<stage>`` carrying the
-round index, and its seconds accumulate in the rollup's
-``stage_seconds``.  The step donates its TrainState argument
-(``donate_argnums=(0,)``), so steady-state serving allocates no new
-state buffers on backends that support donation.
+round index (the pull's also the count of buffers, ``buffers=<n>``),
+and its seconds accumulate in the rollup's ``stage_seconds``.  The
+step donates its TrainState argument (``donate_argnums=(0,)``), so
+steady-state serving allocates no new state buffers on backends that
+support donation.
 
 Run modes:
 
@@ -50,7 +56,9 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
+import math
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -58,6 +66,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.comm.rollup import CommRollup
 from repro.core.frontier import batch_fn_arity
@@ -138,24 +147,66 @@ class Watchdog:
             self._thread = None
 
 
+def pack_metrics(step_fn: Callable):
+    """Wrap ``step_fn`` so that its metrics leave the device as one flat
+    buffer per dtype; returns ``(packed_step, unpack)``.
+
+    ``packed_step(*args)`` returns ``(state, buffers)``: the raveled
+    leaves of each dtype of ``step_fn``'s metric tree, concatenated in
+    leaf order, one buffer per dtype in order of first appearance.  It
+    keeps ``step_fn``'s name (the jitted module stays
+    ``jit_<name>``).  The layout (treedef, each leaf's buffer, offset and
+    shape) is recorded while it traces, so packing adds no trace or
+    compile; the step's metric tree must keep one structure across
+    traces, as a session's does.  ``unpack(buffers)`` reads each buffer
+    to the host once and returns the metric tree of host numpy arrays
+    with the keys, shapes, dtypes and bits ``jax.device_get`` gives.
+    """
+    layout = []
+
+    @functools.wraps(step_fn)
+    def packed_step(*args, **kwargs):
+        state, metrics = step_fn(*args, **kwargs)
+        leaves, treedef = jax.tree_util.tree_flatten(metrics)
+        groups: dict = {}
+        slots = []
+        for leaf in map(jnp.asarray, leaves):
+            parts = groups.setdefault(leaf.dtype, [])
+            slots.append((list(groups).index(leaf.dtype),
+                          sum(p.size for p in parts), leaf.shape))
+            parts.append(leaf.ravel())
+        layout[:] = [treedef, slots]
+        return state, tuple(jnp.concatenate(p) for p in groups.values())
+
+    def unpack(buffers):
+        treedef, slots = layout
+        host = [np.asarray(b) for b in buffers]
+        return treedef.unflatten(
+            [host[i][off:off + math.prod(shape)].reshape(shape)
+             for i, off, shape in slots])
+
+    return packed_step, unpack
+
+
 class _StageClock:
     """Times the stages of served rounds.
 
-    ``with clock(stage, k):`` opens a profiler span ``fleet.<stage>``
-    carrying the round index (``round=k``) and adds the block's
-    ``perf_counter`` time to the stage's running total; :meth:`take`
-    hands the totals over and starts new ones.  The spans land in the
-    same trace as the device's ops (an inactive profiler makes them
-    near-free); the totals feed the rollup's ``stage_seconds``.
+    ``with clock(stage, k, **meta):`` opens a profiler span
+    ``fleet.<stage>`` carrying the round index (``round=k``) and any
+    further ``meta``, and adds the block's ``perf_counter`` time to the
+    stage's running total; :meth:`take` hands the totals over and starts
+    new ones.  The spans land in the same trace as the device's ops (an
+    inactive profiler makes them near-free); the totals feed the
+    rollup's ``stage_seconds``.
     """
 
     def __init__(self):
         self._seconds: dict = {}
 
     @contextlib.contextmanager
-    def __call__(self, stage: str, k: int):
+    def __call__(self, stage: str, k: int, **meta):
         t = time.perf_counter()
-        with jax.profiler.TraceAnnotation(f"fleet.{stage}", round=k):
+        with jax.profiler.TraceAnnotation(f"fleet.{stage}", round=k, **meta):
             yield
         self._seconds[stage] = (self._seconds.get(stage, 0.0)
                                 + time.perf_counter() - t)
@@ -173,7 +224,9 @@ class FleetSession:
     step_fn:
         The UNjitted ``(state, batch) -> (state, metrics)`` train step
         (``make_triggered_train_step`` output); the session jits it
-        with a donated state argument.
+        with a donated state argument, its metrics packed into one
+        device buffer per dtype (:func:`pack_metrics`) so that a round's
+        pull is one transfer per dtype, started at dispatch.
     state:
         Initial TrainState (``init_train_state``).
     batch_fn:
@@ -191,7 +244,9 @@ class FleetSession:
         Base PRNG key for the observation stream.
     on_round:
         Optional ``on_round(round_index, metrics_dict)`` host callback
-        (logging, file sinks); runs outside the rollup lock.
+        (logging, file sinks); runs outside the rollup lock.  The dict
+        holds host numpy arrays, as ``jax.device_get`` of the step's
+        metrics would.
     options:
         :class:`SessionOptions` durability knobs.  When ``ckpt_dir`` is
         set and ``resume`` is on, construction restores the latest
@@ -203,7 +258,8 @@ class FleetSession:
                  rollup: CommRollup, *, key=None,
                  on_round: Optional[Callable] = None,
                  options: Optional[SessionOptions] = None):
-        self._step = jax.jit(step_fn, donate_argnums=(0,))
+        packed_step, self._unpack = pack_metrics(step_fn)
+        self._step = jax.jit(packed_step, donate_argnums=(0,))
         self._state = state
         self._batch_fn = batch_fn
         with_round = batch_fn_arity(batch_fn) == 2
@@ -299,9 +355,12 @@ class FleetSession:
             with stage("sample", k):
                 batch = self._sample(self._key, k)
             while not self._stop.is_set() and (target == 0 or k < target):
-                # 1. dispatch round k (async — returns device futures)
+                # 1. dispatch round k (async — returns device futures);
+                # its metric buffers start home as soon as the step ends
                 with stage("dispatch", k):
-                    self._state, metrics = self._step(self._state, batch)
+                    self._state, buffers = self._step(self._state, batch)
+                    for b in buffers:
+                        b.copy_to_host_async()
                 # 2. sample round k+1's observations in the device's shadow
                 # (one dispatch, queued behind step k)
                 if target == 0 or k + 1 < target:
@@ -310,9 +369,9 @@ class FleetSession:
                 # 3. wait for round k on the device, pull its metrics
                 # (the transfers alone), roll up
                 with stage("wait", k):
-                    jax.block_until_ready(metrics)
-                with stage("pull", k):
-                    metrics = jax.device_get(metrics)
+                    jax.block_until_ready(buffers)
+                with stage("pull", k, buffers=len(buffers)):
+                    metrics = self._unpack(buffers)
                 with stage("rollup", k):
                     self.rollup.update(metrics)
                 if self._watchdog is not None:

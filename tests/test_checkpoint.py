@@ -8,6 +8,7 @@ complete checkpoint continues the EXACT trajectory the uninterrupted
 run would have produced — bitwise params, bitwise net_state (rows and
 (rows, line) payload buffers alike), strictly monotone rollup counters.
 """
+import inspect
 import json
 import os
 
@@ -278,6 +279,62 @@ def test_session_resume_rejects_slot_mismatch(tmp_path):
     a.run(rounds=2)
     with pytest.raises(CheckpointError):
         _session(SLOT_SPECS["ef"], options=opts)
+
+
+def _mixed_dtype_session(on_round=None):
+    """A toy step whose metrics hold an int32 leaf and float32 leaves."""
+
+    def train_step(state, batch):
+        w = state["w"] + 0.1 * batch
+        return {"w": w}, {"loss": jnp.sum(w * w), "agent_w": w,
+                          "num_pos": jnp.sum(w > 0).astype(jnp.int32)}
+
+    return FleetSession(train_step, {"w": jnp.zeros(M)},
+                        lambda key: jax.random.normal(key, (M,)),
+                        CommRollup(), key=jax.random.key(5),
+                        on_round=on_round)
+
+
+def _linreg_fleet_session(on_round=None):
+    from repro.launch.session import build_linreg_fleet_session
+
+    return build_linreg_fleet_session(seed=2, on_round=on_round)
+
+
+PACKED_CASES = {
+    "linreg_m64": (_linreg_fleet_session, 1),
+    "net_retx_tuple": (lambda on_round=None: _session(
+        SLOT_SPECS["net_retx_tuple"], on_round=on_round), 1),
+    "mixed_dtype": (_mixed_dtype_session, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_session_packed_metrics_equal_device_get(case):
+    """Each round's metrics come home as one buffer per dtype, and the
+    dict the session hands on equals ``jax.device_get`` of the plain
+    jitted step's metrics on the same state and batches: same keys,
+    shapes and dtypes, equal bit for bit."""
+    build, n_buffers = PACKED_CASES[case]
+    got = []
+    sess = build(on_round=lambda k, m: got.append(m))
+    plain = jax.jit(inspect.unwrap(sess._step))
+    state = jax.tree_util.tree_map(jnp.copy, sess.state)
+    _, buffers = sess._step(jax.tree_util.tree_map(jnp.copy, state),
+                            sess._sample(sess._key, 0))
+    assert len(buffers) == n_buffers
+    assert sess.run(4) == 4 and len(got) == 4
+    for k, m in enumerate(got):
+        state, want = plain(state, sess._sample(sess._key, k))
+        want = jax.device_get(want)
+        assert jax.tree_util.tree_structure(m) == (
+            jax.tree_util.tree_structure(want))
+        for a, b in zip(jax.tree_util.tree_leaves(m),
+                        jax.tree_util.tree_leaves(want), strict=True):
+            assert isinstance(a, np.ndarray)
+            assert (a.shape, a.dtype) == (b.shape, b.dtype)
+            assert a.tobytes() == b.tobytes(), k
+    assert _leaves_equal(sess.state, state)
 
 
 def test_rollup_state_roundtrip():

@@ -50,6 +50,33 @@ def test_lossy_fleet_step_carries_channel_scope():
     assert set(STAGES) | {"channel"} <= scopes(lowered)
 
 
+def test_fleet_step_module_keeps_its_name():
+    # the benchmark finds the step's device time by this module name
+    assert fleet_lowered().as_text().startswith("module @jit_train_step ")
+
+
+def test_fleet_pull_spans_carry_one_buffer(tmp_path):
+    """A traced run of a few rounds: each round's ``fleet.pull`` span
+    carries its round and ``buffers=1``, the fleet's one f32 buffer."""
+    from jax.profiler import ProfileData
+
+    from repro.launch.session import build_linreg_fleet_session
+
+    sess = build_linreg_fleet_session(seed=3)
+    sess.run(1)  # compiles outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sess.run(3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    pulls = [dict(e.stats) for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events
+             if e.name == "fleet.pull"]
+    assert sorted(p["round"] for p in pulls) == [1, 2, 3]
+    assert all(p["buffers"] == 1 for p in pulls)
+
+
 def test_lm_step_carries_stage_scopes():
     # the toy smollm step: homogeneous vmap path, the grad-norm trigger
     # through the gain_reduce kernel, int8 with error feedback
